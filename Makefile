@@ -44,4 +44,4 @@ bench-check:
 	$(PYTHON) tools/check_bench.py
 
 serve:
-	PYTHONPATH=src $(PYTHON) -m repro.serving.server --arch llama3-8b
+	PYTHONPATH=src $(PYTHON) -m repro.serving.server --reduced

@@ -285,6 +285,8 @@ def main(fast: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fleet", action="store_true",
                     help="run the fleet scenario matrix on the real engine "

@@ -105,4 +105,6 @@ def _paged_engine_decode_row():
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     main(fast=False)

@@ -338,6 +338,8 @@ def main(fast: bool = True, profile: str = None, families=None):
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true",
                     help="CI smoke profile: smallest run that still crosses "

@@ -412,6 +412,8 @@ def run_prefix():
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     import argparse
 
     ap = argparse.ArgumentParser()

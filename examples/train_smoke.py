@@ -24,9 +24,7 @@ def main():
     args = ap.parse_args()
 
     cfg = get_config(args.arch)
-    if args.reduced or cfg.n_params() > 3e8:
-        print(f"note: {args.arch} is {cfg.n_params()/1e9:.1f}B params; "
-              "using the reduced variant on CPU")
+    if args.reduced:
         cfg = cfg.reduced()
 
     out = train(

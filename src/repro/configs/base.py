@@ -138,6 +138,18 @@ class ModelConfig:
         dense_share = self.n_params() - self.n_layers * self.n_experts * 3 * d * f
         return dense_share + self.n_layers * self.top_k * 3 * d * f
 
+    def with_layers(self, n_layers: int) -> "ModelConfig":
+        """Same widths, depth cut to ``n_layers``. The name records the cut
+        (``yi-9b-L24``), so anything keyed by it — the prefix cache's
+        ``arch_key`` among them — tells the cut model from the whole one."""
+        if n_layers == self.n_layers:
+            return self
+        if not 0 < n_layers < self.n_layers:
+            raise ValueError(f"{self.name}: depth cut must be in "
+                             f"[1, {self.n_layers}], got {n_layers}")
+        return dataclasses.replace(self, name=f"{self.name}-L{n_layers}",
+                                   n_layers=n_layers)
+
     # -- reduced variant for CPU smoke tests -------------------------------
     def reduced(self) -> "ModelConfig":
         """Same family, toy size: <=2 layers, d_model<=512, <=4 experts."""

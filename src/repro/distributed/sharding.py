@@ -203,13 +203,9 @@ def replicated(mesh: Mesh):
 # (correctness over cleverness, same policy as the full mesh).
 
 def abstract_mesh(shape: Tuple[int, ...], names: Tuple[str, ...]):
-    """AbstractMesh across jax versions (>=0.5 takes (sizes, names);
-    0.4.x takes a name->size tuple) — shape-only, no devices needed."""
-    AM = jax.sharding.AbstractMesh
-    try:
-        return AM(shape, names)
-    except TypeError:
-        return AM(tuple(zip(names, shape)))
+    """Shape-only mesh (no devices needed): axis sizes ``shape``, axis
+    names ``names``."""
+    return jax.sharding.AbstractMesh(shape, names)
 
 
 def degraded_mesh(mesh: Mesh, lost_shards) -> Mesh:

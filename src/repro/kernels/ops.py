@@ -1,8 +1,10 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``interpret`` auto-selects: real Mosaic lowering on TPU, interpret mode on
-CPU (the kernel body runs in Python/XLA for correctness validation — this
-container's path)."""
+``interpret=None`` auto-selects from the process's default backend: Mosaic
+on TPU, interpret mode elsewhere (the kernel body runs as plain XLA ops, for
+correctness checks on the CPU). The serving engine resolves the choice once
+when it is built (``FamilyExecutor.interpret``); a compile against a
+described TPU from a CPU process must pass ``interpret=False`` itself."""
 from __future__ import annotations
 
 import functools
@@ -15,7 +17,7 @@ from repro.kernels import paged_attention_int8 as _pa8
 from repro.kernels import ssd_scan as _ssd
 
 
-def _default_interpret() -> bool:
+def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
@@ -26,7 +28,7 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, starts=None,
     (B,) int32) masks positions below a per-sequence window start — the
     sliding-window recycling path. See kernel docstring."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     assert q.ndim == 3 and k_pages.ndim == 4
     assert q.shape[1] % k_pages.shape[0] == 0, "H must be a multiple of K"
     return _pa.paged_attention(q, k_pages, v_pages, block_tables, lengths,
@@ -42,7 +44,7 @@ def paged_attention_int8(q, k_pages, k_scales, v_pages, v_scales,
     Same ``starts`` window-lower-bound semantics as ``paged_attention``.
     See kernel docstring."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     assert q.ndim == 3 and k_pages.ndim == 4
     assert k_pages.dtype == jnp.int8 and v_pages.dtype == jnp.int8
     assert q.shape[1] % k_pages.shape[0] == 0, "H must be a multiple of K"
@@ -55,5 +57,5 @@ def paged_attention_int8(q, k_pages, k_scales, v_pages, v_scales,
 def ssd_scan(xdt, a, B, C, chunk: int = 64, interpret: bool | None = None):
     """Mamba-2 chunked SSD scan. See kernel docstring."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     return _ssd.ssd_scan(xdt, a, B, C, chunk=chunk, interpret=interpret)

@@ -1,7 +1,5 @@
-"""Serving launcher: builds the jit'd serve step (prefill or decode) for an
-arch on the production mesh. On real TPU hardware this is the program the
-engine executes per iteration; on this container it is exercised through
-launch/dryrun.py (compile-only) and through RealEngine with reduced configs.
+"""Compile-only check of an arch's serve step (prefill or decode) on the
+production mesh. Serving itself is ``repro.serving.server``.
 
   PYTHONPATH=src python -m repro.launch.serve --arch yi-9b --shape decode_32k --dry-run
 """
@@ -15,41 +13,15 @@ def main():
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", default="decode_32k")
     ap.add_argument("--multi-pod", action="store_true")
-    ap.add_argument("--dry-run", action="store_true",
-                    help="lower+compile only (CPU container path)")
+    ap.add_argument("--dry-run", action="store_true", required=True,
+                    help="lower+compile only, on 512 virtual host devices")
     args = ap.parse_args()
 
-    if args.dry_run:
-        os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                                   " --xla_force_host_platform_device_count=512").strip()
-        from repro.launch.dryrun import dry_run_one
-        rec = dry_run_one(args.arch, args.shape, multi_pod=args.multi_pod)
-        sys.exit(0 if rec["status"] in ("ok", "skipped") else 1)
-
-    # real-serving path (reduced config on CPU; full config on TPU)
-    import numpy as np
-    from repro.configs import get_config
-    from repro.serving.engine import EngineConfig, RealEngine
-    from repro.serving.request import Request
-
-    cfg = get_config(args.arch)
-    if cfg.n_params() > 3e8:
-        print(f"{args.arch} is {cfg.n_params()/1e9:.1f}B params; serving the "
-              "reduced variant on CPU")
-        cfg = cfg.reduced()
-    if cfg.arch_type not in ("dense", "vlm"):
-        print(f"RealEngine serves the dense family; {cfg.arch_type} archs "
-              "serve via api.decode_step (see examples/)")
-        sys.exit(0)
-    eng = RealEngine(cfg, EngineConfig(max_slots=8, max_seq=128), n_instances=2)
-    rng = np.random.default_rng(0)
-    for i in range(12):
-        eng.submit(Request(rid=i, prompt_len=16, max_new_tokens=32,
-                           arrival_time=0.0,
-                           prompt_tokens=rng.integers(1, cfg.vocab_size, 16).tolist()))
-    done = eng.run(3000)
-    print(f"served {len(done)} requests; sample output tokens: "
-          f"{done[0].output_tokens[:8]}")
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
+                               " --xla_force_host_platform_device_count=512").strip()
+    from repro.launch.dryrun import dry_run_one
+    rec = dry_run_one(args.arch, args.shape, multi_pod=args.multi_pod)
+    sys.exit(0 if rec["status"] in ("ok", "skipped") else 1)
 
 
 if __name__ == "__main__":

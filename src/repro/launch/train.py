@@ -1,6 +1,6 @@
 """Training launcher for the production mesh.
 
-  # real run (TPU pod; CPU falls back to a reduced config):
+  # real run (add --reduced for the toy variant on a CPU):
   PYTHONPATH=src python -m repro.launch.train --arch mamba2-130m --steps 100
   # compile-only against the full 16x16 / 2x16x16 mesh:
   PYTHONPATH=src python -m repro.launch.train --arch deepseek-67b --dry-run
@@ -16,6 +16,8 @@ def main():
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--dry-run", action="store_true")
+    ap.add_argument("--reduced", action="store_true",
+                    help="train the toy variant (ModelConfig.reduced)")
     args = ap.parse_args()
 
     if args.dry_run:
@@ -31,8 +33,7 @@ def main():
     from repro.training.train_loop import TrainerConfig, train
 
     cfg = get_config(args.arch)
-    if cfg.n_params() > 3e8:
-        print(f"{args.arch} too large for this host; training reduced variant")
+    if args.reduced:
         cfg = cfg.reduced()
     out = train(cfg, DataConfig(batch_size=4, seq_len=256),
                 OptimizerConfig(warmup_steps=20, total_steps=args.steps),

@@ -14,6 +14,7 @@ cache otherwise.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -37,6 +38,15 @@ def family(cfg: ModelConfig):
 
 
 def init_params(cfg: ModelConfig, rng):
+    """Seeded random weights, built by one jitted program: each weight is
+    drawn in float32 and cast inside the program, so only the cast weights
+    reach device memory (an eager init would hold a float32 copy of every
+    stacked weight at once)."""
+    return _init_params_jit(cfg, rng)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _init_params_jit(cfg: ModelConfig, rng):
     return family(cfg).init_params(cfg, rng)
 
 

@@ -99,10 +99,7 @@ def _constrain_seq(x, seq_dim: int):
     U = _P.UNCONSTRAINED
     spec = [U] * x.ndim
     spec[seq_dim] = SEQ_SHARD_AXIS
-    try:
-        return jax.lax.with_sharding_constraint(x, _P(*spec))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, _P(*spec))
 
 
 def _expand_kv(k, q_heads: int):

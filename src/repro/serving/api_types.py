@@ -200,10 +200,30 @@ class TopologyBlock:
 
 
 @dataclasses.dataclass
+class DeviceInfo:
+    """Where the engine's arrays live (``jax.devices()``), and whether its
+    Pallas kernels run compiled (Mosaic) or in interpret mode."""
+
+    platform: str
+    kind: str
+    count: int
+    interpret: bool
+
+    def to_json(self) -> Dict[str, Any]:
+        return dataclasses.asdict(self)
+
+    @classmethod
+    def from_json(cls, obj: Dict[str, Any]) -> "DeviceInfo":
+        return cls(**{f.name: obj[f.name]
+                      for f in dataclasses.fields(cls)})
+
+
+@dataclasses.dataclass
 class HealthResponse:
     """GET /health — the whole payload (docs/api.md documents it)."""
 
     status: str
+    device: DeviceInfo
     instances: List[InstanceStatus]
     queued: int
     completed: int
@@ -223,6 +243,7 @@ class HealthResponse:
     @classmethod
     def from_json(cls, obj: Dict[str, Any]) -> "HealthResponse":
         kw = {f.name: obj[f.name] for f in dataclasses.fields(cls)}
+        kw["device"] = DeviceInfo.from_json(obj["device"])
         kw["instances"] = [InstanceStatus.from_json(i)
                            for i in obj["instances"]]
         kw["topology"] = TopologyBlock.from_json(obj["topology"])

@@ -1,6 +1,6 @@
 """Real-compute serving engine: continuous batching over actual JAX forward
-passes (reduced models on CPU; the TPU path is the same program jit-compiled
-for the production mesh — launch/serve.py).
+passes, on whatever device JAX gives the process (serving/server.py is the
+front end; chip_smoke.py drives it on one TPU).
 
 ``RealInstance`` is one pipeline instance worth of compute. KevlarFlow's
 mechanisms appear here for real:
@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.distributed import sharding as SH
+from repro.kernels import ops
 from repro.models import api
 from repro.models import paged_decode as PD
 from repro.models.hybrid import state_blob_words
@@ -85,7 +86,9 @@ class EngineConfig:
     replicate: bool = True
     replication: str = "delta"   # "delta" (dirty blocks) | "full" (all blocks)
     pool_blocks: int = 0         # 0 -> primaries + replicas + scratch
-    interpret: Optional[bool] = None  # None -> auto (interpret off-TPU)
+    # Pallas kernels in interpret mode? None -> decided once, when the
+    # executor is built, from the default backend (Mosaic on TPU only)
+    interpret: Optional[bool] = None
     # int8-quantized KV pool: pages (and hybrid state blobs) are stored as
     # int8 + per-row scales, decode runs through the int8 Pallas kernel,
     # and replication ships the quantized bytes — roughly half the HBM read
@@ -176,7 +179,8 @@ class FamilyExecutor:
                 f"{cfg.arch_type!r} (encoder-only / pure-recurrent families "
                 "are not engine targets)")
         temp = ecfg.temperature
-        interp = ecfg.interpret
+        interp = self.interpret = ops.default_interpret() \
+            if ecfg.interpret is None else ecfg.interpret
         quant = ecfg.kv_quant
         # the int8 pool threads its scale side arrays through the same
         # signature (None when kv_quant is off — leafless pytree args, so
@@ -704,28 +708,16 @@ class RealInstance:
         else:
             step_rng = self._rng               # unused by greedy sample()
         pool = self.pool
-        if self.family == "hybrid":
-            out = self._decode(
-                self.params, jnp.asarray(toks), pool.k, pool.v,
-                pool.k_scale, pool.v_scale, pool.blobs, pool.blob_scales,
-                jnp.asarray(self.block_table), jnp.asarray(self.slot_blob),
-                jnp.asarray(self.slot_pos), jnp.asarray(self.slot_base),
-                step_rng)
-            if pool.quantized:
-                (nxt, _, pool.k, pool.v, pool.blobs, pool.k_scale,
-                 pool.v_scale, pool.blob_scales) = out
-            else:
-                nxt, _, pool.k, pool.v, pool.blobs = out
+        out = self._decode(*self.decode_args(toks, step_rng))
+        if self.family == "hybrid" and pool.quantized:
+            (nxt, _, pool.k, pool.v, pool.blobs, pool.k_scale,
+             pool.v_scale, pool.blob_scales) = out
+        elif self.family == "hybrid":
+            nxt, _, pool.k, pool.v, pool.blobs = out
+        elif pool.quantized:
+            (nxt, _, pool.k, pool.v, pool.k_scale, pool.v_scale) = out
         else:
-            out = self._decode(
-                self.params, jnp.asarray(toks), pool.k, pool.v,
-                pool.k_scale, pool.v_scale, jnp.asarray(self.block_table),
-                jnp.asarray(self.slot_pos), jnp.asarray(self.slot_base),
-                step_rng)
-            if pool.quantized:
-                (nxt, _, pool.k, pool.v, pool.k_scale, pool.v_scale) = out
-            else:
-                nxt, _, pool.k, pool.v = out
+            nxt, _, pool.k, pool.v = out
         nxt = np.asarray(nxt)          # the step's single host sync
         finished = []
         for i in active:
@@ -740,6 +732,20 @@ class RealInstance:
                 finished.append(req)
                 self.release(req.rid)
         return finished
+
+    def decode_args(self, toks, rng) -> tuple:
+        """The decode program's arguments for last tokens ``toks`` (B,):
+        the weights, this pool's buffers and the slots' addressing."""
+        pool = self.pool
+        if self.family == "hybrid":
+            return (self.params, jnp.asarray(toks), pool.k, pool.v,
+                    pool.k_scale, pool.v_scale, pool.blobs, pool.blob_scales,
+                    jnp.asarray(self.block_table),
+                    jnp.asarray(self.slot_blob), jnp.asarray(self.slot_pos),
+                    jnp.asarray(self.slot_base), rng)
+        return (self.params, jnp.asarray(toks), pool.k, pool.v,
+                pool.k_scale, pool.v_scale, jnp.asarray(self.block_table),
+                jnp.asarray(self.slot_pos), jnp.asarray(self.slot_base), rng)
 
     def release(self, rid: int):
         """Free a request's engine slot + primary blocks (+ state blob)."""
@@ -943,6 +949,8 @@ class RealEngine:
         # of compiled programs shared by all instances + rejoining spares
         self.params = api.init_params(cfg, jax.random.PRNGKey(seed))
         self.executor = FamilyExecutor(cfg, self.ecfg)
+        # resolved kernel mode (True = Pallas interpreter, False = Mosaic)
+        self.interpret = self.executor.interpret
         # prefill/decode disaggregation: the first max(1, n//2) instances
         # take the prefill role, the rest decode; without it every
         # instance is colocated ("both")

@@ -5,7 +5,7 @@ block-by-block in the background").
 One ``PagedKVPool`` lives on every VirtualNode (for the layer range that
 node owns). Blocks are the unit of allocation, replication, and
 memory-pressure eviction. The pool carries real JAX buffers when the node
-runs real compute (reduced models on CPU), or pure metadata when driven by
+runs real compute (the serving engine), or pure metadata when driven by
 the simulation clock — the allocation/replication logic is identical, which
 is what the tests assert.
 """
@@ -15,17 +15,12 @@ import dataclasses
 import hashlib
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 
-try:  # real-buffer mode is optional (sim benchmarks never touch jax)
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels.paged_attention_int8 import (SCALE_DTYPE,
-                                                    dequantize_pages,
-                                                    quantize_pages)
-except Exception:  # pragma: no cover
-    jax = None
-    jnp = None
+from repro.kernels.paged_attention_int8 import (SCALE_DTYPE,
+                                                dequantize_pages,
+                                                quantize_pages)
 
 
 @dataclasses.dataclass
@@ -133,7 +128,6 @@ class PagedKVPool:
         # let callers pass pool.k_scale etc. uniformly
         self.k_scale = self.v_scale = self.blob_scales = None
         if real:
-            assert jnp is not None
             shape = (n_layers, n_kv_heads, n_blocks, page_size, head_dim)
             if quantized:
                 self.k = jnp.zeros(shape, jnp.int8)
@@ -862,39 +856,43 @@ def _pad_pow2(idx: List[int]) -> List[int]:
     return idx + [idx[-1]] * (n - len(idx))
 
 
-if jax is not None:
-    @jax.jit
-    def _copy_blocks(src_k, src_v, dst_k, dst_v, src_idx, dst_idx):
-        # gather + scatter in one program: XLA fuses the block movement
-        # into a single dispatch, never materializing the gathered blocks
-        return (dst_k.at[:, :, dst_idx].set(src_k[:, :, src_idx]),
-                dst_v.at[:, :, dst_idx].set(src_v[:, :, src_idx]))
+@jax.jit
+def _copy_blocks(src_k, src_v, dst_k, dst_v, src_idx, dst_idx):
+    # gather + scatter in one program: XLA fuses the block movement
+    # into a single dispatch, never materializing the gathered blocks
+    return (dst_k.at[:, :, dst_idx].set(src_k[:, :, src_idx]),
+            dst_v.at[:, :, dst_idx].set(src_v[:, :, src_idx]))
 
-    @jax.jit
-    def _copy_blocks_q(src_k, src_v, src_ks, src_vs,
-                       dst_k, dst_v, dst_ks, dst_vs, src_idx, dst_idx):
-        return (dst_k.at[:, :, dst_idx].set(src_k[:, :, src_idx]),
-                dst_v.at[:, :, dst_idx].set(src_v[:, :, src_idx]),
-                dst_ks.at[:, :, dst_idx].set(src_ks[:, :, src_idx]),
-                dst_vs.at[:, :, dst_idx].set(src_vs[:, :, src_idx]))
 
-    @jax.jit
-    def _copy_blobs(src_pool, dst_pool, src_idx, dst_idx):
-        return dst_pool.at[dst_idx].set(src_pool[src_idx])
+@jax.jit
+def _copy_blocks_q(src_k, src_v, src_ks, src_vs,
+                   dst_k, dst_v, dst_ks, dst_vs, src_idx, dst_idx):
+    return (dst_k.at[:, :, dst_idx].set(src_k[:, :, src_idx]),
+            dst_v.at[:, :, dst_idx].set(src_v[:, :, src_idx]),
+            dst_ks.at[:, :, dst_idx].set(src_ks[:, :, src_idx]),
+            dst_vs.at[:, :, dst_idx].set(src_vs[:, :, src_idx]))
 
-    @jax.jit
-    def _scatter_blocks(k_pool, v_pool, slots, k_blocks, v_blocks):
-        return (k_pool.at[:, :, slots].set(k_blocks),
-                v_pool.at[:, :, slots].set(v_blocks))
 
-    @jax.jit
-    def _scatter_blocks_q(k_pool, v_pool, ks_pool, vs_pool, slots,
-                          k_blocks, v_blocks, k_scales, v_scales):
-        return (k_pool.at[:, :, slots].set(k_blocks),
-                v_pool.at[:, :, slots].set(v_blocks),
-                ks_pool.at[:, :, slots].set(k_scales),
-                vs_pool.at[:, :, slots].set(v_scales))
+@jax.jit
+def _copy_blobs(src_pool, dst_pool, src_idx, dst_idx):
+    return dst_pool.at[dst_idx].set(src_pool[src_idx])
 
-    @jax.jit
-    def _scatter_blobs(blob_pool, slots, blobs):
-        return blob_pool.at[slots].set(blobs)
+
+@jax.jit
+def _scatter_blocks(k_pool, v_pool, slots, k_blocks, v_blocks):
+    return (k_pool.at[:, :, slots].set(k_blocks),
+            v_pool.at[:, :, slots].set(v_blocks))
+
+
+@jax.jit
+def _scatter_blocks_q(k_pool, v_pool, ks_pool, vs_pool, slots,
+                      k_blocks, v_blocks, k_scales, v_scales):
+    return (k_pool.at[:, :, slots].set(k_blocks),
+            v_pool.at[:, :, slots].set(v_blocks),
+            ks_pool.at[:, :, slots].set(k_scales),
+            vs_pool.at[:, :, slots].set(v_scales))
+
+
+@jax.jit
+def _scatter_blobs(blob_pool, slots, blobs):
+    return blob_pool.at[slots].set(blobs)

@@ -6,8 +6,12 @@ GET /health, and the versioned fault-injection admin API
 legacy ``/admin/fail_instance`` / ``/admin/rejoin_instance`` paths remain
 as deprecated aliases).
 
-  PYTHONPATH=src python -m repro.serving.server --arch llama3-8b --port 8080
+  PYTHONPATH=src python -m repro.serving.server --port 8080          # on a TPU
+  PYTHONPATH=src python -m repro.serving.server --reduced --port 8080  # CPU toy
   curl -d '{"prompt_tokens": [1,2,3], "max_tokens": 8}' localhost:8080/v1/completions
+
+By default it serves Yi-9B at its published widths with depth cut to 24 of
+48 layers (``build_parser``/``build_configs``, which chip_smoke.py shares).
 """
 from __future__ import annotations
 
@@ -17,7 +21,11 @@ import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
-from repro.serving.api_types import (DegradationState, FaultSpec,
+import jax
+
+from repro.configs import get_config
+from repro.runtime import enable_compile_cache
+from repro.serving.api_types import (DegradationState, DeviceInfo, FaultSpec,
                                      HealthResponse, InstanceStatus,
                                      TopologyBlock)
 from repro.serving.engine import EngineConfig, RealEngine
@@ -36,6 +44,10 @@ class EngineService:
         self.engine = RealEngine(cfg, ecfg, n_instances=n_instances,
                                  clock=time.time)
         self.cfg = cfg
+        devs = jax.devices()
+        self.device = DeviceInfo(platform=devs[0].platform,
+                                 kind=devs[0].device_kind, count=len(devs),
+                                 interpret=self.engine.interpret)
         self._lock = threading.Lock()
         self._next_rid = 0
         self._events: dict[int, threading.Event] = {}
@@ -60,7 +72,12 @@ class EngineService:
                 ev = self._events.get(req.rid)
                 if ev:
                     ev.set()
-            if not progressed:
+            if progressed:
+                # hand the GIL over between steps: the lock is not fair,
+                # and without a yield this thread re-takes it before a
+                # waiting request, /health or admin call ever can
+                time.sleep(0)
+            else:
                 # idle, or stalled on a standard-mode weight reload: back
                 # off instead of spinning with the lock held. A slot mid-
                 # chunked-prefill IS pending work (its next chunk runs on
@@ -152,7 +169,7 @@ class EngineService:
                 for i in eng.instances]
             topo = eng.control.describe()
             return HealthResponse(
-                status="ok", instances=instances,
+                status="ok", device=self.device, instances=instances,
                 queued=eng.queue_depth(), completed=len(eng.done),
                 recovery_mode=eng.ecfg.recovery,
                 failure_events=[dict(e) for e in eng.failure_events],
@@ -316,10 +333,29 @@ def serve(cfg, ecfg=None, n_instances=2, port=8080):
     return svc, httpd
 
 
-def main():
-    from repro.configs import get_config
+# The deployment served by default: Yi-9B (arXiv:2403.04652) at its
+# published widths, depth cut to 24 of 48 layers, so that its bf16 weights
+# (~9.35 GB) and two instances' KV pools (~0.8 GB each at 8 slots x 1024
+# positions) fit one 16 GB TPU v5e.
+DEFAULT_ARCH = "yi-9b"
+DEFAULT_N_LAYERS = 24
+MAX_SLOTS = 8
+MAX_SEQ = 1024
+# --reduced serves on the CPU, where the Pallas interpreter's decode step
+# grows faster than linearly with the block-table width (7.8 s per engine
+# step at 1024 positions against 2.4 s at 512, reduced Yi-9B, 2 instances)
+REDUCED_MAX_SEQ = 256
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="llama3-8b")
+    ap.add_argument("--arch", default=DEFAULT_ARCH)
+    ap.add_argument("--n-layers", type=int, default=DEFAULT_N_LAYERS,
+                    help="depth cut at published widths; 0 = the published "
+                         "depth")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the toy variant (ModelConfig.reduced: 2 "
+                         "layers, d_model <= 256) — for the CPU")
     ap.add_argument("--port", type=int, default=8080)
     ap.add_argument("--instances", type=int, default=2)
     ap.add_argument("--kv-quant", action="store_true",
@@ -360,16 +396,25 @@ def main():
                     help="intern fully-covered prompt pages in a refcounted "
                          "prefix index; shared prefixes attach by reference "
                          "(copy-on-write) and skip prefill compute")
-    args = ap.parse_args()
+    return ap
+
+
+def build_configs(args):
+    """(ModelConfig, EngineConfig) from ``build_parser`` arguments. The model
+    size is exactly what the arguments say: never picked from a parameter
+    count or from the backend."""
     cfg = get_config(args.arch)
-    if cfg.n_params() > 3e8:
-        print(f"{args.arch}: serving the reduced variant on CPU")
+    if args.reduced:
         cfg = cfg.reduced()
+    elif args.n_layers:
+        cfg = cfg.with_layers(args.n_layers)
     # sliding-window archs serve any max_seq (block recycling keeps only
     # the attention window resident) — no capping needed
     if args.disaggregate and args.prefill_chunk <= 0:
         args.prefill_chunk = 8      # streaming needs chunked prefill
-    ecfg = EngineConfig(kv_quant=args.kv_quant, recovery=args.recovery,
+    ecfg = EngineConfig(max_slots=MAX_SLOTS,
+                        max_seq=REDUCED_MAX_SEQ if args.reduced else MAX_SEQ,
+                        kv_quant=args.kv_quant, recovery=args.recovery,
                         auto_rejoin=args.auto_rejoin,
                         rejoin_delay=args.rejoin_delay,
                         reload_penalty=args.reload_penalty,
@@ -379,9 +424,19 @@ def main():
                         placement=args.placement,
                         n_shards=args.n_shards,
                         replicate=(args.recovery == "kevlarflow"))
+    return cfg, ecfg
+
+
+def main():
+    enable_compile_cache()
+    args = build_parser().parse_args()
+    cfg, ecfg = build_configs(args)
     svc, httpd = serve(cfg, ecfg, n_instances=args.instances, port=args.port)
     print(f"KevlarFlow serving {cfg.name} on :{args.port} "
-          f"({args.instances} instances, {args.recovery} recovery). "
+          f"({args.instances} instances, {args.recovery} recovery) on "
+          f"{svc.device.count} x {svc.device.kind} "
+          f"({svc.device.platform}, Pallas "
+          f"{'interpret' if svc.device.interpret else 'Mosaic'}). "
           f"POST /v1/completions")
     try:
         httpd.serve_forever()

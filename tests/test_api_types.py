@@ -6,9 +6,9 @@ import json
 
 import pytest
 
-from repro.serving.api_types import (DegradationState, FaultSpec,
-                                     HealthResponse, InstanceStatus,
-                                     TopologyBlock)
+from repro.serving.api_types import (DegradationState, DeviceInfo,
+                                     FaultSpec, HealthResponse,
+                                     InstanceStatus, TopologyBlock)
 
 # -- FaultSpec --------------------------------------------------------------
 
@@ -121,7 +121,10 @@ def test_topology_block_roundtrip():
 
 def test_health_response_roundtrip():
     h = HealthResponse(
-        status="ok", instances=[_instance(0), _instance(1, lost=[0])],
+        status="ok",
+        device=DeviceInfo(platform="tpu", kind="TPU v5 lite", count=1,
+                          interpret=False),
+        instances=[_instance(0), _instance(1, lost=[0])],
         queued=3, completed=17, recovery_mode="kevlarflow",
         failure_events=[{"instance": 1, "granularity": "shard",
                          "shard_idx": 0, "mttr": -1.0}],
@@ -133,3 +136,5 @@ def test_health_response_roundtrip():
     # the wire shape is plain JSON: dicts/lists/scalars all the way down
     assert wire["instances"][1]["degradation"]["state"] == "DEGRADED"
     assert wire["topology"]["states"]["1"] == "DEGRADED"
+    assert wire["device"] == {"platform": "tpu", "kind": "TPU v5 lite",
+                              "count": 1, "interpret": False}

@@ -64,6 +64,38 @@ def test_reduced_variants(name):
         assert r.n_experts <= 4
 
 
+def test_with_layers_cuts_depth_only():
+    full = get_config("yi-9b")
+    cut = full.with_layers(24)
+    assert cut.name == "yi-9b-L24" and cut.n_layers == 24
+    for k in ("d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff",
+              "vocab_size", "dtype", "page_size"):
+        assert getattr(cut, k) == getattr(full, k)
+    assert full.with_layers(48) is full
+    for bad in (0, 49):
+        with pytest.raises(ValueError):
+            full.with_layers(bad)
+
+
+def test_served_size_comes_from_the_arguments():
+    """The server's size is what its flags say — never a parameter-count
+    threshold or the backend: the default is Yi-9B cut to 24 layers at
+    published widths, --reduced the toy, --n-layers 0 the whole model."""
+    from repro.serving import server
+
+    def configs(*argv):
+        return server.build_configs(server.build_parser().parse_args(argv))
+
+    cfg, ecfg = configs()
+    assert cfg == get_config("yi-9b").with_layers(24)
+    assert (ecfg.max_slots, ecfg.max_seq) == (8, 1024)
+    assert ecfg.replicate and ecfg.recovery == "kevlarflow"
+    assert configs("--reduced")[0] == get_config("yi-9b").reduced()
+    assert configs("--n-layers", "0")[0] == get_config("yi-9b")
+    assert configs("--arch", "qwen1.5-0.5b", "--n-layers", "0")[0] == \
+        get_config("qwen1.5-0.5b")
+
+
 def test_moe_active_params():
     c = get_config("mixtral-8x7b")
     assert c.n_active_params() < c.n_params()

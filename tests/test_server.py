@@ -5,6 +5,7 @@ import threading
 import urllib.error
 import urllib.request
 
+import jax
 import numpy as np
 import pytest
 
@@ -86,6 +87,10 @@ def test_health(server):
     assert h["recovery_mode"] == "kevlarflow"
     assert h["failure_events"] == []      # nothing injected yet
     assert all("queued" in i for i in h["instances"])
+    dev = jax.devices()[0]
+    assert h["device"] == {"platform": dev.platform, "kind": dev.device_kind,
+                           "count": len(jax.devices()),
+                           "interpret": dev.platform != "tpu"}
 
 
 def test_failover_under_live_traffic(server):

@@ -12,18 +12,8 @@ from repro.launch import specs as sp
 # 1 CPU device -> build abstract meshes for spec computation only
 DEVS = np.array(jax.devices() * 1)
 
-
-def _abstract_mesh(shape, names):
-    try:
-        # jax >= 0.5: AbstractMesh(axis_sizes, axis_names)
-        return jax.sharding.AbstractMesh(shape, names)
-    except TypeError:
-        # jax 0.4.x: AbstractMesh(((name, size), ...))
-        return jax.sharding.AbstractMesh(tuple(zip(names, shape)))
-
-
-MESH = _abstract_mesh((16, 16), ("data", "model"))
-MESH3 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = sh.abstract_mesh((16, 16), ("data", "model"))
+MESH3 = sh.abstract_mesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_param_spec_2d_weight():
