@@ -67,6 +67,7 @@ from repro.kernels import ops
 from repro.models import api
 from repro.models import paged_decode as PD
 from repro.models.hybrid import state_blob_words
+from repro.serving import tracing
 from repro.serving.api_types import FaultSpec
 from repro.serving.controlplane import ControlPlane
 from repro.serving.kvcache import PagedKVPool
@@ -76,6 +77,19 @@ from repro.serving.transport import (TransportChannel, collect_dirty,
                                      host_table_growth, reconcile_replica)
 
 SCRATCH_RID = -7  # pool rid reserved for the idle-slot scratch block
+
+
+def _wait_stats(req: Request) -> dict:
+    """An admission span's waits in microseconds, while a profiler
+    records: ``lock_wait_us`` in the front end (arrival - submit; -1 for a
+    request that did not come through ``EngineService``) and ``queue_us``
+    in the engine's admission queue (admit - arrival)."""
+    if not tracing.enabled():
+        return {}
+    lock = round((req.arrival_time - req.submit_time) * 1e6) \
+        if req.submit_time >= 0 else -1
+    return {"lock_wait_us": lock,
+            "queue_us": round((req.admit_time - req.arrival_time) * 1e6)}
 
 
 @dataclasses.dataclass
@@ -496,31 +510,34 @@ class RealInstance:
                 if self.family == "hybrid" else None,
             }
             return True
-        if self.family == "hybrid":
-            logits, k_seq, v_seq, blob = self._prefill(
-                self.params, jnp.asarray(toks), jnp.int32(n))
-            bref = self.pool.blob_ref(req.rid)
-            self.pool.write_blob(bref.slot, blob[0])
-            self.slot_blob[slot] = bref.slot
-        else:
-            logits, k_seq, v_seq = self._prefill(
-                self.params, jnp.asarray(toks), jnp.int32(n))
-        self.prefill_compute_tokens += n   # monolithic: always full compute
-        # windowed archs: only the window-covering tail pages were allocated
-        # (refs[0].logical_idx > 0 for long prompts) — write just those.
-        # Shared prefix pages (lo > 0) already hold these exact bytes and
-        # are never written in place; the diverging page goes private first
-        first_page = refs[0].logical_idx
-        lo = skip_pages if cow_page < 0 else cow_page
-        if cow_page >= 0:
-            self.pool.ensure_private(req.rid, cow_page)
-        if lo < len(refs):
-            span = (first_page + lo) * page
-            self.pool.write_blocks(
-                [r.slot for r in refs[lo:]],
-                *PD.pack_pages(k_seq[:, span:], v_seq[:, span:],
-                               len(refs) - lo, page))
-        self._seat(slot, req, refs, logits, now)
+        with tracing.span("prefill", rid=req.rid, tokens=n, bucket=bucket,
+                          **_wait_stats(req)):
+            if self.family == "hybrid":
+                logits, k_seq, v_seq, blob = self._prefill(
+                    self.params, jnp.asarray(toks), jnp.int32(n))
+                bref = self.pool.blob_ref(req.rid)
+                self.pool.write_blob(bref.slot, blob[0])
+                self.slot_blob[slot] = bref.slot
+            else:
+                logits, k_seq, v_seq = self._prefill(
+                    self.params, jnp.asarray(toks), jnp.int32(n))
+            self.prefill_compute_tokens += n   # monolithic: full compute
+            # windowed archs: only the window-covering tail pages were
+            # allocated (refs[0].logical_idx > 0 for long prompts) — write
+            # just those. Shared prefix pages (lo > 0) already hold these
+            # exact bytes and are never written in place; the diverging
+            # page goes private first
+            first_page = refs[0].logical_idx
+            lo = skip_pages if cow_page < 0 else cow_page
+            if cow_page >= 0:
+                self.pool.ensure_private(req.rid, cow_page)
+            if lo < len(refs):
+                span = (first_page + lo) * page
+                self.pool.write_blocks(
+                    [r.slot for r in refs[lo:]],
+                    *PD.pack_pages(k_seq[:, span:], v_seq[:, span:],
+                                   len(refs) - lo, page))
+            self._seat(slot, req, refs, logits, now)
         return True
 
     def _first_token(self, req: Request, logits, now: float):
@@ -583,45 +600,48 @@ class RealInstance:
             c = min(self.chunk, job["bucket"])
             c0 = job["done"]
             take = min(c, n - c0)
-            tc = np.zeros((1, c), np.int32)
-            hi = min(c0 + c, job["bucket"])
-            tc[0, :hi - c0] = job["toks"][0, c0:hi]
-            if self.family == "hybrid":
-                (logits, job["k_buf"], job["v_buf"], job["rstates"],
-                 blob) = self._prefill_chunk(
-                    self.params, jnp.asarray(tc), jnp.int32(c0),
-                    jnp.int32(take), job["k_buf"], job["v_buf"],
-                    job["rstates"])
-            else:
-                logits, job["k_buf"], job["v_buf"] = self._prefill_chunk(
-                    self.params, jnp.asarray(tc), jnp.int32(c0),
-                    jnp.int32(take), job["k_buf"], job["v_buf"])
-                blob = None
-            job["done"] = c0 + take
-            self.prefill_compute_tokens += take
-            req.prefill_progress = job["done"] / n
-            ran += 1
-            final = job["done"] >= n
-            self._write_ready_pages(job, final)
-            if final:
+            with tracing.span("prefill.chunk", rid=req.rid, tokens=take,
+                              start=c0, **_wait_stats(req)):
+                tc = np.zeros((1, c), np.int32)
+                hi = min(c0 + c, job["bucket"])
+                tc[0, :hi - c0] = job["toks"][0, c0:hi]
                 if self.family == "hybrid":
-                    bref = self.pool.blob_ref(req.rid)
-                    self.pool.write_blob(bref.slot, blob[0])
-                    self.slot_blob[slot] = bref.slot
-                if self.handoff_mode:
-                    # disaggregation: the prompt's pages (and blob) are in
-                    # the pool but the slot parks in PREFILL state — the
-                    # engine streams the remaining pages to the decode
-                    # target and seats the request THERE. The first token
-                    # is sampled now, so TTFT means the same thing it does
-                    # colocated: prefill completion.
-                    self._first_token(req, logits, now)
-                    self.ready_handoffs.append(
-                        {"slot": slot, "req": req, "refs": job["refs"],
-                         "logits": logits})
+                    (logits, job["k_buf"], job["v_buf"], job["rstates"],
+                     blob) = self._prefill_chunk(
+                        self.params, jnp.asarray(tc), jnp.int32(c0),
+                        jnp.int32(take), job["k_buf"], job["v_buf"],
+                        job["rstates"])
                 else:
-                    self._seat(slot, req, job["refs"], logits, now)
-                del self.prefill_jobs[slot]
+                    logits, job["k_buf"], job["v_buf"] = self._prefill_chunk(
+                        self.params, jnp.asarray(tc), jnp.int32(c0),
+                        jnp.int32(take), job["k_buf"], job["v_buf"])
+                    blob = None
+                job["done"] = c0 + take
+                self.prefill_compute_tokens += take
+                req.prefill_progress = job["done"] / n
+                ran += 1
+                final = job["done"] >= n
+                self._write_ready_pages(job, final)
+                if final:
+                    if self.family == "hybrid":
+                        bref = self.pool.blob_ref(req.rid)
+                        self.pool.write_blob(bref.slot, blob[0])
+                        self.slot_blob[slot] = bref.slot
+                    if self.handoff_mode:
+                        # disaggregation: the prompt's pages (and blob)
+                        # are in the pool but the slot parks in PREFILL
+                        # state — the engine streams the remaining pages
+                        # to the decode target and seats the request
+                        # THERE. The first token is sampled now, so TTFT
+                        # means the same thing it does colocated: prefill
+                        # completion.
+                        self._first_token(req, logits, now)
+                        self.ready_handoffs.append(
+                            {"slot": slot, "req": req, "refs": job["refs"],
+                             "logits": logits})
+                    else:
+                        self._seat(slot, req, job["refs"], logits, now)
+                    del self.prefill_jobs[slot]
         return ran
 
     def _write_ready_pages(self, job: dict, final: bool):
@@ -669,68 +689,85 @@ class RealInstance:
                   if r >= 0 and self.requests[r].state == RequestState.DECODE]
         if not active:
             return []
-        toks = np.zeros(self.ecfg.max_slots, np.int32)
-        for i in active:
-            rid = self.slot_rid[i]
-            toks[i] = self.requests[rid].output_tokens[-1]
-            # sliding window: pages fully below the window of the position
-            # this step writes are recycled BEFORE allocating the new page
-            # (freed slots are the first candidates for reuse); their hosted
-            # replicas are retired on the ring peer by the engine
-            recycled = self.pool.recycle_out_of_window(rid) \
-                if self.window else []
-            self.pending_retires.extend(
-                (rid, r.logical_idx) for r in recycled)
-            # account the KV row this step writes; may open a fresh block
-            # (marks the receiving block dirty -> delta replication unit)
-            try:
-                ref = self.pool.append_token(rid)
-            except MemoryError:
-                self.pool.evict_replicas_for_pressure(1)
-                ref = self.pool.append_token(rid)
-            self.pending_retires.extend(
-                (r.rid, r.logical_idx)
-                for r in self.pool.drain_pending_recycles())
-            if self.window:
-                # window-relative row: column j = j-th resident page
-                table = self.pool.table(rid)
-                row = np.full(self.pages_per_seq, self.scratch, np.int32)
-                row[:len(table)] = [r.slot for r in table]
-                self.block_table[i] = row
-                self.slot_base[i] = \
-                    table[0].logical_idx * self.pool.page_size
-            else:
-                self.block_table[i, ref.logical_idx] = ref.slot
-            # the recurrent state advances every step -> blob always dirty
-            self.pool.mark_blob_dirty(rid)
-        if self.ecfg.temperature > 0:
-            self._rng, step_rng = jax.random.split(self._rng)
-        else:
-            step_rng = self._rng               # unused by greedy sample()
-        pool = self.pool
-        out = self._decode(*self.decode_args(toks, step_rng))
-        if self.family == "hybrid" and pool.quantized:
-            (nxt, _, pool.k, pool.v, pool.blobs, pool.k_scale,
-             pool.v_scale, pool.blob_scales) = out
-        elif self.family == "hybrid":
-            nxt, _, pool.k, pool.v, pool.blobs = out
-        elif pool.quantized:
-            (nxt, _, pool.k, pool.v, pool.k_scale, pool.v_scale) = out
-        else:
-            nxt, _, pool.k, pool.v = out
-        nxt = np.asarray(nxt)          # the step's single host sync
-        finished = []
-        for i in active:
-            req = self.requests[self.slot_rid[i]]
-            req.output_tokens.append(int(nxt[i]))
-            req.generated += 1
-            self.slot_pos[i] += 1
-            if req.generated >= req.max_new_tokens or \
-                    self.slot_pos[i] >= self.ecfg.max_seq - 1:
-                req.state = RequestState.DONE
-                req.finish_time = self._stamp(now)
-                finished.append(req)
-                self.release(req.rid)
+        # ctx_tokens: the attention's work this step, for the profile
+        ctx = {"ctx_tokens": sum(int(self.slot_pos[i]) + 1 for i in active)} \
+            if tracing.enabled() else {}
+        with tracing.span("decode", instance=self.instance_id,
+                          slots=len(active), **ctx):
+            with tracing.span("decode.prepare"):
+                toks = np.zeros(self.ecfg.max_slots, np.int32)
+                for i in active:
+                    rid = self.slot_rid[i]
+                    toks[i] = self.requests[rid].output_tokens[-1]
+                    # sliding window: pages fully below the window of the
+                    # position this step writes are recycled BEFORE
+                    # allocating the new page (freed slots are the first
+                    # candidates for reuse); their hosted replicas are
+                    # retired on the ring peer by the engine
+                    recycled = self.pool.recycle_out_of_window(rid) \
+                        if self.window else []
+                    self.pending_retires.extend(
+                        (rid, r.logical_idx) for r in recycled)
+                    # account the KV row this step writes; may open a
+                    # fresh block (marks the receiving block dirty ->
+                    # delta replication unit)
+                    try:
+                        ref = self.pool.append_token(rid)
+                    except MemoryError:
+                        self.pool.evict_replicas_for_pressure(1)
+                        ref = self.pool.append_token(rid)
+                    self.pending_retires.extend(
+                        (r.rid, r.logical_idx)
+                        for r in self.pool.drain_pending_recycles())
+                    if self.window:
+                        # window-relative row: column j = j-th resident page
+                        table = self.pool.table(rid)
+                        row = np.full(self.pages_per_seq, self.scratch,
+                                      np.int32)
+                        row[:len(table)] = [r.slot for r in table]
+                        self.block_table[i] = row
+                        self.slot_base[i] = \
+                            table[0].logical_idx * self.pool.page_size
+                    else:
+                        self.block_table[i, ref.logical_idx] = ref.slot
+                    # the recurrent state advances every step -> blob
+                    # always dirty
+                    self.pool.mark_blob_dirty(rid)
+                if self.ecfg.temperature > 0:
+                    self._rng, step_rng = jax.random.split(self._rng)
+                else:
+                    step_rng = self._rng       # unused by greedy sample()
+            pool = self.pool
+            with tracing.span("decode.launch"):
+                out = self._decode(*self.decode_args(toks, step_rng))
+                if self.family == "hybrid" and pool.quantized:
+                    (nxt, _, pool.k, pool.v, pool.blobs, pool.k_scale,
+                     pool.v_scale, pool.blob_scales) = out
+                elif self.family == "hybrid":
+                    nxt, _, pool.k, pool.v, pool.blobs = out
+                elif pool.quantized:
+                    (nxt, _, pool.k, pool.v, pool.k_scale, pool.v_scale) = out
+                else:
+                    nxt, _, pool.k, pool.v = out
+            # the step's single host sync; the chip idles in it too, while
+            # the uploads land and the program starts, and while the
+            # tokens come back
+            with tracing.span("decode.sync"):
+                nxt = np.asarray(nxt)
+            with tracing.span("decode.finish") as fin:
+                finished = []
+                for i in active:
+                    req = self.requests[self.slot_rid[i]]
+                    req.output_tokens.append(int(nxt[i]))
+                    req.generated += 1
+                    self.slot_pos[i] += 1
+                    if req.generated >= req.max_new_tokens or \
+                            self.slot_pos[i] >= self.ecfg.max_seq - 1:
+                        req.state = RequestState.DONE
+                        req.finish_time = self._stamp(now)
+                        finished.append(req)
+                        self.release(req.rid)
+                fin.set_metadata(finished=len(finished))
         return finished
 
     def decode_args(self, toks, rng) -> tuple:
@@ -1140,6 +1177,14 @@ class RealEngine:
         everywhere, replicate deltas. Returns the number of requests that
         made forward progress (0 while stalled or idle — the service loop
         backs off instead of spinning)."""
+        active = sum(len(i.requests) for i in self.instances if i.alive)
+        with tracing.span("engine.step", active=active) as sp:
+            progressed, admitted = self._step()
+            sp.set_metadata(admitted=admitted)
+        return progressed
+
+    def _step(self) -> tuple:
+        """``step``'s body; returns (progressed, requests admitted)."""
         self.t = self.clock() if self.clock is not None else self.t + 1.0
         _t0 = time.perf_counter()
         # async shipping: flush the PREVIOUS step's staged jobs (replica
@@ -1164,18 +1209,18 @@ class RealEngine:
             else:
                 self.rejoin_instance(due)
         if self.t < self.stall_until:
-            return 0       # standard recovery: group-wide weight reload
+            return 0, 0    # standard recovery: group-wide weight reload
         alive = [i for i in self.instances if i.alive]
         # rerouting part 1: arrivals go to the least-loaded alive instance
         while self.waiting and alive:
             self._route(self.waiting.pop(0))
         # each instance admits from its OWN queue...
-        progressed = 0
+        admitted = 0
         for inst in alive:
             q = self.queues[inst.instance_id]
             while q and inst.free_slots() and inst.admit(q[0], self.t):
                 q.pop(0)
-                progressed += 1
+                admitted += 1
         # ...then (rerouting part 2) queued work an instance cannot place —
         # full pool, busy slots — flows to any peer with headroom: an
         # instance can have free slots but a full pool, and vice versa
@@ -1190,7 +1235,8 @@ class RealEngine:
                     continue
                 while q and other.free_slots() and other.admit(q[0], self.t):
                     q.pop(0)
-                    progressed += 1
+                    admitted += 1
+        progressed = admitted
         n_active = sum(len(i.requests) for i in alive)
         for inst in alive:
             self.active_request_steps += len(inst.requests)
@@ -1225,6 +1271,7 @@ class RealEngine:
             q = self.queues[inst.instance_id]
             while q and inst.free_slots() and inst.admit(q[0], self.t):
                 q.pop(0)
+                admitted += 1
                 progressed += 1
         if self.ecfg.replicate:
             self._replicate()
@@ -1245,7 +1292,7 @@ class RealEngine:
                 (n_active, time.perf_counter() - _t0, cap_frac))
             if len(self.step_samples) > 20000:      # bound long-run memory
                 del self.step_samples[:10000]
-        return progressed
+        return progressed, admitted
 
     def _drop_replica_of(self, rid: int):
         meta = self.replica_meta.pop(rid, None)
@@ -1297,6 +1344,17 @@ class RealEngine:
                 self.repl_shared_hostings_total += 1
 
     def _stage_replication(self):
+        staged = self.transport.staged["repl"]
+        before = (staged.msgs, staged.blocks, staged.bytes)
+        with tracing.span("repl.stage") as sp:
+            self._stage_deltas()
+            sp.set_metadata(jobs=staged.msgs - before[0],
+                            blocks=staged.blocks - before[1],
+                            bytes=staged.bytes - before[2])
+
+    def _stage_deltas(self):
+        """Host this pass's dirty blocks on each ring target and stage
+        their copies."""
         full = self.ecfg.replication == "full"
         pc = self.ecfg.prefix_cache
         for inst in self.instances:
@@ -1652,9 +1710,15 @@ class RealEngine:
         spec.validate(len(self.instances), self.ecfg.n_shards)
         if spec.if_busy and not self.instances[spec.instance_id].requests:
             return None
-        if spec.granularity == "shard":
-            return self._apply_shard_fault(spec.instance_id, spec.shard_idx)
-        return self._apply_instance_fault(spec.instance_id)
+        with tracing.span("fault", instance=spec.instance_id,
+                          granularity=spec.granularity) as sp:
+            if spec.granularity == "shard":
+                resumed = self._apply_shard_fault(spec.instance_id,
+                                                  spec.shard_idx)
+            else:
+                resumed = self._apply_instance_fault(spec.instance_id)
+            sp.set_metadata(resumed=len(resumed))
+        return resumed
 
     def recover(self, spec: FaultSpec):
         """THE recovery entry point (``POST /v1/admin/recover``): instance
@@ -1664,9 +1728,12 @@ class RealEngine:
         restoring a non-degraded one — raise ValueError (HTTP 409)."""
         spec.validate(len(self.instances), self.ecfg.n_shards,
                       for_recover=True)
-        if spec.granularity == "shard":
-            return self._recover_shards(spec.instance_id)
-        return self._recover_instance(spec.instance_id)
+        # every rejoin comes through here, the planner's own included
+        with tracing.span("recover", instance=spec.instance_id,
+                          granularity=spec.granularity):
+            if spec.granularity == "shard":
+                return self._recover_shards(spec.instance_id)
+            return self._recover_instance(spec.instance_id)
 
     def fail_instance(self, instance_id: int) -> List[int]:
         """Kill a whole instance (thin wrapper over ``apply_fault``)."""

@@ -29,6 +29,8 @@ class Request:
     instance_id: Optional[int] = None
 
     # metrics (absolute times; -1 = not yet)
+    submit_time: float = -1.0                   # handed to the front end,
+                                                # before its lock
     admit_time: float = -1.0                    # prefill started (last admit)
     first_token_time: float = -1.0
     finish_time: float = -1.0

@@ -87,11 +87,16 @@ class EngineService:
                 time.sleep(0.002 if busy else 0.01)
 
     def submit(self, prompt_tokens, max_tokens: int) -> Request:
+        # stamped before the lock, which the loop holds a whole engine
+        # step: arrival - submit is the front end's wait, admit - arrival
+        # the engine's admission queue
+        submitted = time.time()
         with self._lock:
             rid = self._next_rid
             self._next_rid += 1
             req = Request(rid=rid, prompt_len=len(prompt_tokens),
                           max_new_tokens=max_tokens, arrival_time=time.time(),
+                          submit_time=submitted,
                           prompt_tokens=list(prompt_tokens))
             self._events[rid] = threading.Event()
             self.engine.submit(req)

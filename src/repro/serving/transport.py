@@ -40,6 +40,8 @@ from typing import Dict, List, Optional
 
 import jax
 
+from repro.serving import tracing
+
 KINDS = ("repl", "handoff")
 
 
@@ -139,24 +141,30 @@ class TransportChannel:
         toward the shipped totals."""
         pending, self.pending = self.pending, []
         shipped = []
-        for msg in pending:
-            dst = self.instances[msg["dst"]]
-            dst_alive = (self.view.is_alive(msg["dst"])
-                         if self.view is not None else dst.alive)
-            if not dst_alive or msg["dst"] == exclude:
-                self.dropped[msg["kind"]].add(msg)
-                continue
-            src = self.instances[msg["src"]]
-            src.pool.copy_blocks_to(dst.pool, *msg["blocks"])
-            src.pool.copy_blobs_to(dst.pool, *msg["blobs"])
-            self.shipped[msg["kind"]].add(msg)
-            if self.view is not None and self.view.is_degraded(msg["dst"]):
-                self.shipped_degraded[msg["kind"]].add(msg)
-            if msg["on_shipped"] is not None:
-                msg["on_shipped"]()
-            shipped.append(dst)
-        if block and shipped:
-            jax.block_until_ready([d.pool.k for d in shipped])
+        nbytes = 0
+        with tracing.span("transport.flush") as sp:
+            for msg in pending:
+                dst = self.instances[msg["dst"]]
+                dst_alive = (self.view.is_alive(msg["dst"])
+                             if self.view is not None else dst.alive)
+                if not dst_alive or msg["dst"] == exclude:
+                    self.dropped[msg["kind"]].add(msg)
+                    continue
+                src = self.instances[msg["src"]]
+                src.pool.copy_blocks_to(dst.pool, *msg["blocks"])
+                src.pool.copy_blobs_to(dst.pool, *msg["blobs"])
+                self.shipped[msg["kind"]].add(msg)
+                if self.view is not None and \
+                        self.view.is_degraded(msg["dst"]):
+                    self.shipped_degraded[msg["kind"]].add(msg)
+                if msg["on_shipped"] is not None:
+                    msg["on_shipped"]()
+                shipped.append(dst)
+                nbytes += msg["nbytes"]
+            if block and shipped:
+                jax.block_until_ready([d.pool.k for d in shipped])
+            sp.set_metadata(jobs=len(shipped), bytes=nbytes,
+                            dropped=len(pending) - len(shipped))
 
 
 def reconcile_replica(src_pool, dst_pool, peer: int, rid: int, table,
