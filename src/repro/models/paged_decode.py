@@ -354,12 +354,19 @@ def _window_addressing(cfg, page: int, block_tables, pos, base):
     rel = pos - base
     dst_block = block_tables[rows, rel // page]          # (B,) physical slots
     dst_off = rel % page
-    lengths = rel + 1
-    starts = None
-    if cfg.sliding_window:
-        starts = jnp.maximum(jnp.maximum(pos + 1 - cfg.sliding_window, 0)
-                             - base, 0)
+    lengths, starts = attended_range(pos, base, cfg.sliding_window, jnp)
     return dst_block, dst_off, lengths, starts
+
+
+def attended_range(pos, base, window: int, xp=jnp):
+    """(lengths, starts) of what a decode step writing ABSOLUTE ``pos``
+    attends to, relative to ``base``: positions [starts, lengths). ``starts``
+    is None without a window. ``xp`` is ``jnp`` in the program, ``np`` for
+    the host's own count of the kernel's work."""
+    lengths = pos - base + 1
+    if not window:
+        return lengths, None
+    return lengths, xp.maximum(xp.maximum(pos + 1 - window, 0) - base, 0)
 
 
 def decode_step_paged(cfg, params, token, k_pages, v_pages, block_tables,

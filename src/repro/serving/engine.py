@@ -63,7 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.distributed import sharding as SH
-from repro.kernels import ops
+from repro.kernels import ops, paged_attention
 from repro.models import api
 from repro.models import paged_decode as PD
 from repro.models.hybrid import state_blob_words
@@ -693,7 +693,7 @@ class RealInstance:
         ctx = {"ctx_tokens": sum(int(self.slot_pos[i]) + 1 for i in active)} \
             if tracing.enabled() else {}
         with tracing.span("decode", instance=self.instance_id,
-                          slots=len(active), **ctx):
+                          slots=len(active), **ctx) as dec:
             with tracing.span("decode.prepare"):
                 toks = np.zeros(self.ecfg.max_slots, np.int32)
                 for i in active:
@@ -737,6 +737,10 @@ class RealInstance:
                     self._rng, step_rng = jax.random.split(self._rng)
                 else:
                     step_rng = self._rng       # unused by greedy sample()
+            if tracing.enabled():
+                # the kernel's blocks, from the tables this step launches
+                # with (recycling may have moved a window's base)
+                dec.set_metadata(**self.kv_blocks())
             pool = self.pool
             with tracing.span("decode.launch"):
                 out = self._decode(*self.decode_args(toks, step_rng))
@@ -769,6 +773,17 @@ class RealInstance:
                         self.release(req.rid)
                 fin.set_metadata(finished=len(finished))
         return finished
+
+    def kv_blocks(self) -> dict:
+        """``kv_blocks``: the blocks the paged kernel fetches per layer in a
+        decode step from this state, over every slot it walks (idle ones
+        too); ``kv_blocks_table``: the blocks its block table holds."""
+        lengths, starts = PD.attended_range(self.slot_pos, self.slot_base,
+                                            self.window, np)
+        fetched, table = paged_attention.kv_blocks(
+            lengths, starts, self.pool.page_size, self.pages_per_seq,
+            self.cfg.n_kv_heads, self.cfg.head_dim)
+        return {"kv_blocks": fetched, "kv_blocks_table": table}
 
     def decode_args(self, toks, rng) -> tuple:
         """The decode program's arguments for last tokens ``toks`` (B,):

@@ -13,6 +13,8 @@ except ImportError:
     HAVE_HYPOTHESIS = False
 
 from repro.kernels.ops import paged_attention, ssd_scan
+from repro.kernels.paged_attention import (BLOCK_TOKENS, BLOCK_VMEM_BYTES,
+                                           kv_blocks, pages_per_block)
 from repro.kernels.ref import paged_attention_ref, ssd_scan_ref
 
 
@@ -122,6 +124,104 @@ def test_paged_attention_starts_none_is_zero():
     out2 = paged_attention(q, kp, vp, bt, ln,
                            jnp.zeros_like(ln), interpret=True)
     np.testing.assert_array_equal(np.asarray(out1), np.asarray(out2))
+
+
+# The kernel walks blocks of ``pages_per_block`` pages (16 at page 16): a
+# 20-page table leaves a partial last block of 4 pages.
+BLOCK_CASES = {
+    # name: (lengths, starts, what the table holds past the last valid page)
+    "partial_last_block": ([320, 300, 17], None, None),
+    "page_boundary": ([16, 48, 272], None, None),
+    "block_boundary": ([256, 257, 255], None, None),
+    "idle_slots": ([1, 1, 200], None, None),
+    "start_skips_block": ([310, 320, 5], [260, 300, 0], None),
+    "tail_out_of_range": ([40, 256, 299], None, "out_of_range"),
+    "tail_scratch": ([40, 256, 299], [3, 0, 290], "scratch"),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", list(BLOCK_CASES))
+def test_paged_attention_blocks(case, dtype):
+    """Block edges, idle slots, skipped leading blocks and table tails
+    against the oracle; a table's entries past the last valid page are
+    never read, so what they hold cannot change the output."""
+    lengths, starts, tail = BLOCK_CASES[case]
+    b, page, pps = len(lengths), 16, 20
+    assert pages_per_block(page, pps, 2, 128) == 16
+    q, kp, vp, bt, _ = _paged_case(b, 8, 2, 128, page, pps, dtype, seed=3)
+    n_phys = kp.shape[1]
+    bt = np.array(bt)
+    scratch = min(set(range(n_phys)) - set(bt.ravel()))   # in no table
+    ln = np.asarray(lengths, np.int32)
+    bt[ln == 1] = scratch                    # idle slots: the scratch block
+    st = None if starts is None else jnp.asarray(starts, jnp.int32)
+    clean = jnp.asarray(bt)
+    out = paged_attention(q, kp, vp, clean, jnp.asarray(ln), st,
+                          interpret=True)
+    ref = paged_attention_ref(q, kp, vp, clean, jnp.asarray(ln), st)
+    tol = 1e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=tol, atol=tol)
+    if tail is not None:
+        poisoned = bt.copy()
+        for i, n in enumerate(ln):
+            poisoned[i, -(-n // page):] = \
+                n_phys + 1000 if tail == "out_of_range" else scratch
+        out2 = paged_attention(q, kp.at[:, scratch].set(1e4),
+                               vp.at[:, scratch].set(1e4),
+                               jnp.asarray(poisoned), jnp.asarray(ln), st,
+                               interpret=True)
+        np.testing.assert_array_equal(np.asarray(out2), np.asarray(out))
+
+
+def test_paged_attention_dmas_do_not_race():
+    """Under the TPU interpreter, which tracks every DMA and semaphore and
+    fills fresh scratch with NaN: no copy races a read of its buffer, and
+    the result equals the oracle's, so no unfetched byte reaches it."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+    from repro.kernels import paged_attention as pa
+    q, kp, vp, bt, _ = _paged_case(3, 8, 2, 128, 16, 20, jnp.float32, seed=4)
+    ln = jnp.asarray([320, 1, 270], jnp.int32)
+    st = jnp.asarray([260, 0, 3], jnp.int32)
+    out = pa.paged_attention(q, kp, vp, bt, ln, st,
+                             interpret=pltpu.InterpretParams(
+                                 detect_races=True,
+                                 uninitialized_memory="nan"))
+    out.block_until_ready()
+    assert not interpret_pallas_call.races.races_found
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(paged_attention_ref(q, kp, vp, bt, ln, st)),
+        rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("shape,ppb", [
+    ((16, 64, 4, 128), 16),       # Yi-9B served: 256 positions a block
+    ((16, 129, 1, 256), 16),      # RecurrentGemma local ring: partial last
+    ((16, 256, 4, 128), 16),      # 32 slots x 4096
+    ((16, 64, 8, 128), 16),       # Mixtral: 8 KV heads
+    ((8, 8, 2, 64), 8),           # reduced: never past the table
+    ((16, 64, 16, 256), 4),       # wide heads: the VMEM budget binds
+])
+def test_pages_per_block(shape, ppb):
+    page, table_pages, kheads, head_dim = shape
+    n = pages_per_block(page, table_pages, kheads, head_dim)
+    assert n == ppb and 1 <= n <= table_pages
+    assert n * page <= BLOCK_TOKENS
+    # K and V, two buffers each, even at 4 bytes an element
+    assert 2 * 2 * kheads * n * page * head_dim * 4 <= BLOCK_VMEM_BYTES
+
+
+def test_kv_blocks_counts_blocks_with_a_valid_position():
+    """The host's count of the kernel's blocks: blocks of 256 positions
+    that hold a position in [start, length), at least one a slot."""
+    lengths = np.array([1, 256, 257, 1024, 700, 600])
+    starts = np.array([0, 0, 0, 0, 512, 100])
+    fetched, table = kv_blocks(lengths, starts, 16, 64, 4, 128)
+    assert (fetched, table) == (1 + 1 + 2 + 4 + 1 + 3, 6 * 4)
+    assert kv_blocks(lengths, None, 16, 64, 4, 128)[0] == 1 + 1 + 2 + 4 + 3 + 3
 
 
 # --------------------------------------------------------------------------

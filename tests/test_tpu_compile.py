@@ -54,6 +54,7 @@ KERNEL_SHAPES = {
     "yi-9b": (8, 32, 4, 128, 16, 64),              # GQA 8:1, 1024 positions
     "recurrentgemma-9b": (8, 16, 1, 256, 16, 64),  # MQA, head_dim 256
     "yi-9b-b32-4k": (32, 32, 4, 128, 16, 256),     # 32 slots x 4096
+    "mixtral-8x7b": (8, 32, 8, 128, 16, 64),       # K = 8, the widest block
 }
 
 

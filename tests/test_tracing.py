@@ -14,6 +14,7 @@ import pytest
 from jax.profiler import TraceAnnotation
 
 from repro.configs import get_config
+from repro.kernels.paged_attention import pages_per_block
 from repro.serving import tracing
 from repro.serving.engine import EngineConfig, RealEngine
 from repro.serving.request import Request
@@ -148,16 +149,38 @@ def test_decode_stats_match_the_benchmarks_attention_work(cfg, tmp_path):
         assert d.stats["slots"] == b.stats["slots"]
         flops, _ = paged_attention_cost(conf, [d.stats["ctx_tokens"]])
         assert flops * cfg.n_layers == b.stats["attn_flops"]
+        assert 0 < d.stats["kv_blocks"] <= d.stats["kv_blocks_table"]
+
+
+class _Recorded:
+    """A span that also keeps its stats, those given at its end too."""
+
+    def __init__(self, name, stats, on_end=None):
+        self.name, self.stats, self.on_end = name, dict(stats), on_end
+        self._span = TraceAnnotation(tracing.PREFIX + name)
+
+    def __enter__(self):
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        return self._span.__exit__(*exc)
+
+    def set_metadata(self, **stats):
+        self.stats.update(stats)
+        if self.on_end:
+            self.on_end(self)
 
 
 def test_no_stat_is_computed_without_a_profiler(cfg, monkeypatch):
     """With no profiler running the spans are opened with integer stats
-    only: ``ctx_tokens`` and the admission waits wait for ``enabled()``."""
+    only: ``ctx_tokens``, the kernel's block counts and the admission waits
+    wait for ``enabled()``."""
     opened = []
 
     def record(name, **stats):
-        opened.append((name, stats))
-        return TraceAnnotation(tracing.PREFIX + name)
+        opened.append(_Recorded(name, stats))
+        return opened[-1]
 
     monkeypatch.setattr(tracing, "span", record)
     assert not tracing.enabled()
@@ -166,11 +189,49 @@ def test_no_stat_is_computed_without_a_profiler(cfg, monkeypatch):
     for r in _reqs(cfg, 2, seed=4, out=4):
         eng.submit(r)
     eng.run(100)
-    names = {n for n, _ in opened}
+    names = {s.name for s in opened}
     assert {"engine.step", "prefill", "decode", "decode.prepare",
             "repl.stage", "transport.flush"} <= names
-    for name, stats in opened:
-        assert not {"ctx_tokens", "lock_wait_us", "queue_us"} & set(stats)
+    for s in opened:
+        assert not {"ctx_tokens", "kv_blocks", "kv_blocks_table",
+                    "lock_wait_us", "queue_us"} & set(s.stats)
+
+
+def test_decode_counts_the_kernels_blocks(cfg, monkeypatch):
+    """``kf.decode``'s ``kv_blocks`` is the blocks of ``pages_per_block``
+    pages that hold a position below each slot's length, counted from
+    ``slot_pos``/``slot_base`` over every slot (an idle one walks one), and
+    never more than ``kv_blocks_table``, the table's blocks."""
+    eng = RealEngine(cfg, EngineConfig(max_slots=4, max_seq=512),
+                     n_instances=2)
+    seen = []
+
+    def launched(span):
+        # the state the kernel is launched with
+        inst = eng.instances[span.stats["instance"]]
+        seen.append((span.stats, inst.slot_pos.copy(), inst.slot_base.copy()))
+
+    def record(name, **stats):
+        return _Recorded(name, stats, launched if name == "decode" else None)
+
+    monkeypatch.setattr(tracing, "span", record)
+    monkeypatch.setattr(tracing, "enabled", lambda: True)
+    for r in _reqs(cfg, 3, seed=6, prompt=12, out=4) + \
+            _reqs(cfg, 1, seed=7, prompt=300, out=4):
+        eng.submit(r)
+    eng.run(100)
+    table = eng.instances[0].pages_per_seq
+    ppb = pages_per_block(cfg.page_size, table, cfg.n_kv_heads, cfg.head_dim)
+    bk = ppb * cfg.page_size
+    assert seen
+    for stats, pos, base in seen:
+        want = sum(len({p // bk for p in range(b, q + 1)})
+                   for q, b in zip(pos, base))
+        assert stats["kv_blocks"] == want
+        assert stats["kv_blocks_table"] == len(pos) * -(-table // ppb)
+        assert stats["kv_blocks"] <= stats["kv_blocks_table"]
+    assert any(s["kv_blocks"] < s["kv_blocks_table"] for s, _, _ in seen)
+    assert any(s["kv_blocks"] > len(p) for s, p, _ in seen)
 
 
 @pytest.fixture(scope="module")
